@@ -136,9 +136,10 @@ def merge_cover_rows(begins, ends, exact, group_idx, extra_b, extra_e,
 
     ``impl`` selects the merge+cover core: "xla" runs the lax.scan
     reference below; "pallas" runs the fused VMEM-resident kernel
-    (`kernels.merge_cover`, interpreter mode off-TPU) — bit-identical by
-    the parity suite, selected via ``IndexSpec.kernel_impl``. The gather /
-    concat / sort prologue is shared.
+    (`kernels.merge_cover`: compiled on TPU, the Pallas interpreter in the
+    CPU tests) — bit-identical by the parity suite, selected via
+    ``IndexSpec.kernel_impl``. The gather / concat / sort prologue is
+    shared.
 
     Returns per-group slabs ``[B, w_out]`` covered to ≤ k intervals.
     """
@@ -164,9 +165,9 @@ def merge_cover_rows(begins, ends, exact, group_idx, extra_b, extra_e,
 
     if impl == "pallas":
         from repro.kernels.merge_cover import merge_cover_sorted_rows
-        return merge_cover_sorted_rows(
-            cb, ce, cx, k=k, w_out=w_out,
-            interpret=jax.default_backend() != "tpu")
+        from repro.kernels.ops import _on_tpu
+        return merge_cover_sorted_rows(cb, ce, cx, k=k, w_out=w_out,
+                                       interpret=not _on_tpu())
 
     def row(b, e, x):
         ob, oe, ox, cnt = _merge_sorted_row(b, e, x)

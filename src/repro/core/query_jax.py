@@ -129,7 +129,7 @@ class DeviceQueryEngine:
         self.comp = jnp.asarray(self.packed.comp)  # replicate the full table
         self.use_pallas = use_pallas
         # resolved fused-kernel core of the sparse frontier step ("auto" →
-        # pallas on TPU/GPU, xla on CPU); needs the gather-fused layout,
+        # pallas on TPU, xla elsewhere); needs the gather-fused layout,
         # ops.expand_frontier falls back to the XLA loop without it
         self.kernel_impl = ops.resolve_kernel_impl(kernel_impl)
         self.phase2_chunk = phase2_chunk

@@ -26,11 +26,15 @@ row resident in VMEM and makes both phases one pass:
   per-output masked min/max/any reductions over the slot axis.
 
 Grid: 1-D over row tiles of BLOCK_B lanes; `tree_merge.py`'s constant-width
-chunks map 1:1 onto grid tiles. VMEM per tile = 7 · m · BLOCK_B · 4 B
-(3 input slabs + 4 scratch planes) ≈ 7.3 MiB at the widest single-shot
-width m = 2049 and BLOCK_B = 128 — under half of VMEM, leaving room for
-double-buffered pipelining. Bit-identical to the XLA path by construction;
-asserted in tests/test_merge_cover_kernel.py.
+chunks map 1:1 onto grid tiles. VMEM per tile is about 20 int32 planes of
+[m, BLOCK_B] — double-buffered input slabs, 4 scratch planes and pass 2's
+temporaries: the TPU compiler asks 19.75 MiB at the widest single-shot
+width m = 2049 and BLOCK_B = 128, above Mosaic's 16 MiB default scoped
+limit, so the call requests its own (`vmem_limit_bytes`). Mosaic cannot
+carry or select i1 vectors, so loop carries and shifted planes are int32
+0/1; bools stay local to one expression. Bit-identical to the XLA path by
+construction; asserted in tests/test_merge_cover_kernel.py, and compiled
+for a v5e in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -45,6 +49,20 @@ from jax.experimental.pallas import tpu as pltpu
 # a constant by the kernel trace, which pallas_call rejects
 INVALID = 2**31 - 1
 DEFAULT_BLOCK_B = 128
+# Mosaic's default scoped-VMEM limit (16 MiB) is below what pass 2 needs at
+# the widest single-shot width; the kernel asks for room by plane count
+_VMEM_PLANES = 32
+_VMEM_FLOOR = 16 << 20
+_VMEM_CEIL = 100 << 20          # of v5e's 128 MiB
+
+
+def vmem_limit_bytes(m: int, block_b: int) -> int:
+    """Scoped-VMEM request for one tile: room for ``_VMEM_PLANES`` int32
+    [m, block_b] planes (double-buffered input slabs, the four scratch
+    planes and pass 2's temporaries — the TPU compiler measured ~20 at
+    m = 2049), clamped to [16 MiB, 100 MiB]."""
+    need = _VMEM_PLANES * m * block_b * 4
+    return int(min(max(need, _VMEM_FLOOR), _VMEM_CEIL))
 
 
 def _merge_cover_kernel(b_ref, e_ref, x_ref,
@@ -53,18 +71,23 @@ def _merge_cover_kernel(b_ref, e_ref, x_ref,
     bq = b_ref.shape[1]
 
     # ---- pass 1: union-merge recurrence (sequential over the m slots) ----
+    # holed/opened ride the loop as int32 0/1 planes: Mosaic cannot carry
+    # i1 vectors through a loop (it refuses the i8 -> i1 truncation), so
+    # every bool stays local to one step
     def step(i, carry):
-        cb, ce, ece, holed, opened = carry
-        bi = pl.load(b_ref, (pl.dslice(i, 1), slice(None)))
-        ei = pl.load(e_ref, (pl.dslice(i, 1), slice(None)))
-        xi = pl.load(x_ref, (pl.dslice(i, 1), slice(None))) != 0
+        cb, ce, ece, holed_i, opened_i = carry
+        holed = holed_i != 0
+        idx = pl.ds(i, 1)
+        bi = b_ref[idx, :]
+        ei = e_ref[idx, :]
+        xi = x_ref[idx, :] != 0
         valid = bi < INVALID
         cur_exact = (~holed) & (ece >= ce)
 
         touching = bi == ce + 1
         overlap = bi <= ce
         type_ok = cur_exact == xi
-        do_merge = opened & valid & (overlap | (touching & type_ok))
+        do_merge = (opened_i != 0) & valid & (overlap | (touching & type_ok))
         do_open = valid & ~do_merge
 
         ce_m = jnp.maximum(ce, ei)
@@ -75,37 +98,39 @@ def _merge_cover_kernel(b_ref, e_ref, x_ref,
         ce_n = jnp.where(do_open, ei, jnp.where(do_merge, ce_m, ce))
         ece_n = jnp.where(do_open, jnp.where(xi, ei, bi - 1),
                           jnp.where(do_merge, ece_m, ece))
-        holed_n = jnp.where(do_open, False,
-                            jnp.where(do_merge, holed_m, holed))
+        # bool-valued select spelled as logic (an i1 select does not lower)
+        holed_n = (~do_open) & ((do_merge & holed_m) | (~do_merge & holed))
         exf = (~holed_n) & (ece_n >= ce_n)   # exact flag if closed after i
 
-        idx = (pl.dslice(i, 1), slice(None))
-        pl.store(cb_s, idx, cb_n)
-        pl.store(ce_s, idx, ce_n)
-        pl.store(ex_s, idx, exf.astype(jnp.int32))
-        pl.store(op_s, idx, do_open.astype(jnp.int32))
-        return cb_n, ce_n, ece_n, holed_n, opened | valid
+        cb_s[idx, :] = cb_n
+        ce_s[idx, :] = ce_n
+        ex_s[idx, :] = exf.astype(jnp.int32)
+        op_s[idx, :] = do_open.astype(jnp.int32)
+        return (cb_n, ce_n, ece_n, holed_n.astype(jnp.int32),
+                opened_i | valid.astype(jnp.int32))
 
     init = (jnp.zeros((1, bq), jnp.int32),
             jnp.full((1, bq), -1, jnp.int32),
             jnp.full((1, bq), -2, jnp.int32),
-            jnp.ones((1, bq), jnp.bool_),
-            jnp.zeros((1, bq), jnp.bool_))
+            jnp.ones((1, bq), jnp.int32),
+            jnp.zeros((1, bq), jnp.int32))
     jax.lax.fori_loop(0, m, step, init)
 
     # ---- pass 2: top-gap cover over the in-place merged groups ----------
+    # slot shifts run on the int32 planes: Mosaic cannot concatenate i1
     b = b_ref[...]
     valid = b < INVALID                       # valid slots form a prefix
-    opn = op_s[...] != 0
+    op_i = op_s[...]
+    opn = op_i != 0
     cbm = cb_s[...]
     cem = ce_s[...]
     exm = ex_s[...] != 0
 
-    pad_f = jnp.zeros((1, bq), jnp.bool_)
-    open_next = jnp.concatenate([opn[1:], pad_f], axis=0)
-    valid_next = jnp.concatenate([valid[1:], pad_f], axis=0)
+    open_next = jnp.concatenate(
+        [op_i[1:], jnp.zeros((1, bq), jnp.int32)], axis=0) != 0
     b_next = jnp.concatenate(
         [b[1:], jnp.full((1, bq), INVALID, jnp.int32)], axis=0)
+    valid_next = b_next < INVALID
     is_last = valid & (open_next | ~valid_next)
 
     # gap between a group and its successor lives on the group's last slot
@@ -114,35 +139,35 @@ def _merge_cover_kernel(b_ref, e_ref, x_ref,
     # keep the k-1 largest gaps; ties pick the smallest slot — the exact
     # set the reference's stable argsort(-gaps) rank < k-1 keeps
     rows = jax.lax.broadcasted_iota(jnp.int32, (m, bq), 0)
-    keep = jnp.zeros((m, bq), jnp.bool_)
+    keep = jnp.zeros((m, bq), jnp.int32)      # 0/1 plane of kept cuts
     gw = gap
     for _ in range(k - 1):
         mx = jnp.max(gw, axis=0, keepdims=True)
         cand = (gw == mx) & (mx > -1)
         selrow = jnp.min(jnp.where(cand, rows, m), axis=0, keepdims=True)
         sel = rows == selrow
-        keep |= sel
+        keep = jnp.where(sel, 1, keep)
         gw = jnp.where(sel, -2, gw)
 
     # output-group id = exclusive prefix count of kept cuts above each slot
-    c = keep.astype(jnp.int32)
+    c = keep
     sh = 1
     while sh < m:
         c = c + jnp.concatenate(
             [jnp.zeros((sh, bq), jnp.int32), c[:-sh]], axis=0)
         sh *= 2
-    out_id = c - keep.astype(jnp.int32)       # exclusive
+    out_id = c - keep                         # exclusive
 
+    last_x = (is_last & exm).astype(jnp.int32)
     for j in range(w_out):
         mj = valid & (out_id == j)
         nbj = jnp.min(jnp.where(mj, cbm, INVALID), axis=0, keepdims=True)
         nej = jnp.max(jnp.where(mj, cem, -1), axis=0, keepdims=True)
-        szj = jnp.sum((mj & opn).astype(jnp.int32), axis=0, keepdims=True)
-        anyx = jnp.any(mj & is_last & exm, axis=0, keepdims=True)
-        nxj = (szj == 1) & anyx
+        szj = jnp.sum(jnp.where(mj, op_i, 0), axis=0, keepdims=True)
+        anyx = jnp.max(jnp.where(mj, last_x, 0), axis=0, keepdims=True)
         nb_ref[j:j + 1, :] = jnp.where(szj > 0, nbj, INVALID)
         ne_ref[j:j + 1, :] = jnp.where(szj > 0, nej, -1)
-        nx_ref[j:j + 1, :] = nxj.astype(jnp.int32)
+        nx_ref[j:j + 1, :] = jnp.where(szj == 1, anyx, 0)
 
     cnt = jnp.sum(opn.astype(jnp.int32), axis=0, keepdims=True)
     cnt_ref[...] = jnp.minimum(cnt, k)
@@ -180,6 +205,8 @@ def merge_cover_sorted_rows(cb, ce, cx, *, k: int, w_out: int,
         out_shape=[jax.ShapeDtypeStruct((w_out, bp), jnp.int32)] * 3
         + [jax.ShapeDtypeStruct((1, bp), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((m, block_b), jnp.int32)] * 4,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes(m, block_b)),
         interpret=interpret,
     )(*args)
     return nb.T[:B], ne.T[:B], nx.T[:B] != 0, cnt[0, :B]

@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Each op auto-selects ``interpret=True`` off-TPU (this container is CPU-only;
-TPU is the compile target), and performs the layout prep the kernels expect.
-The wrappers are the ONLY entry points the rest of the system uses.
+The kernels compile for TPU. Off-TPU each op runs them in the Pallas
+interpreter (``interpret=True``) — the CPU test path; a deployment on a
+chip asserts the backend up front (``chip_smoke.py``) so the interpreter is
+never taken there in silence. The wrappers also do the layout prep the
+kernels expect, and are the ONLY entry points the rest of the system uses.
 """
 from __future__ import annotations
 
@@ -39,16 +41,15 @@ def resolve_kernel_impl(impl: str) -> str:
     """Resolve the ``IndexSpec.kernel_impl`` knob to a concrete core.
 
     "xla"/"pallas" are explicit; "auto" picks the fused Pallas kernels on
-    an accelerator backend (TPU/GPU) and the XLA reference path on CPU,
-    where the kernels would run under the (slower-to-trace) interpreter.
-    Explicit "pallas" on CPU still works — interpreter mode — and is how
-    CI exercises the fused kernels without an accelerator.
+    TPU — the only backend they compile for — and the XLA reference path
+    everywhere else. Explicit "pallas" off-TPU runs the kernels in the
+    Pallas interpreter, which is how the tests exercise them on the CPU.
     """
     if impl not in KERNEL_IMPLS:
         raise ValueError(
             f"kernel_impl must be one of {KERNEL_IMPLS}, got {impl!r}")
     if impl == "auto":
-        return "pallas" if jax.default_backend() in ("tpu", "gpu") else "xla"
+        return "pallas" if _on_tpu() else "xla"
     return impl
 
 

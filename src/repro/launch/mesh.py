@@ -9,27 +9,18 @@ device state. Single pod: 16x16 = 256 chips (TPU v5e pod slice), axes
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    jax.sharding.AxisType) only exist on newer jax; Auto is the default
-    there, so older versions just omit the argument."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(n_devices: int | None = None, model: int = 1):
     """Small mesh over whatever devices exist (tests)."""
     n = n_devices or len(jax.devices())
     assert n % model == 0
-    return make_mesh_compat((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

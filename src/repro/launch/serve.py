@@ -363,6 +363,8 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen-len", type=int, default=16)
     args = ap.parse_args()
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "reachability":
         # clamp before construction: IndexSpec validates max_batch >= min_bucket
         args.min_bucket = min(args.min_bucket, args.max_batch)

@@ -56,16 +56,10 @@ DEFAULT_RULES: Dict[str, MeshAxes] = {
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """jax.shard_map across jax versions: newer jax exposes it top-level
-    with ``check_vma``; older jax has jax.experimental.shard_map.shard_map
-    with ``check_rep``. Semantics of the two flags match for our uses."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-    from jax.experimental.shard_map import shard_map as sm_old
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
+    """``jax.shard_map`` with the varying-manual-axes check off by default
+    (the serving kernels psum masked partials the checker cannot see)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def mesh_axis_size(mesh: Mesh, axes: MeshAxes) -> int:

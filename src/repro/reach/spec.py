@@ -65,7 +65,7 @@ class IndexSpec:
     frontier_cap_max: int = 1 << 18
     # fused-kernel core for the two hot loops (merge-cover build + frontier
     # step): xla = reference paths, pallas = fused VMEM kernels, auto =
-    # pallas on TPU/GPU and xla on CPU. An EXECUTION knob, not a build
+    # pallas on TPU and xla elsewhere. An EXECUTION knob, not a build
     # field — both impls are bit-identical (parity suites), so artifacts
     # built either way are interchangeable.
     kernel_impl: str = "auto"
@@ -263,7 +263,7 @@ class IndexSpec:
                         choices=KERNEL_IMPLS, dest="kernel_impl",
                         help="fused-kernel core for merge-cover build and "
                              "frontier expansion: auto = pallas on "
-                             "TPU/GPU, xla on CPU (bit-identical either "
+                             "TPU, xla elsewhere (bit-identical either "
                              "way)")
         ap.add_argument("--max-batch", type=int, default=d.max_batch,
                         help="QuerySession micro-batch ceiling")

@@ -61,7 +61,8 @@ def test_shardmap_matches_gather_tokens_sharded():
     capacity factor high enough that nothing drops, the EP-local dispatch
     must match the global-gather reference exactly."""
     out = run_with_devices(COMMON + r"""
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx = ShardingCtx(mesh)
 ref = jax.jit(lambda lp, x: tf._moe_ffn_gather(cfg, lp, x, ctx))(lp, x)
 got = jax.jit(lambda lp, x: tf._moe_ffn_shardmap(cfg, lp, x, ctx))(lp, x)
@@ -88,7 +89,8 @@ def test_shardmap_matches_gather_tokens_replicated():
     """Decode mode: tokens replicated, expert mlp dim sharded over data
     (weight-capacity-bound serving). Combine psums over (model, data)."""
     out = run_with_devices(COMMON + r"""
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx_serve = ShardingCtx(mesh, {"mlp": "data"})
 xb = x[:, :1]                                   # decode: [B, 1, D]
 ref = jax.jit(lambda lp, x: tf._moe_ffn_gather(cfg, lp, x, ctx_serve))(lp, xb)
@@ -105,7 +107,8 @@ def test_shardmap_drops_match_gshard_semantics():
     outputs and drop AT MOST as many tokens as the worst shard's overflow
     (sanity: no NaNs, zero rows only for dropped tokens)."""
     out = run_with_devices(COMMON + r"""
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx = ShardingCtx(mesh)
 cfg_tight = replace(cfg, moe=replace(cfg.moe, capacity_factor=0.5))
 y = jax.jit(lambda lp, x: tf._moe_ffn_shardmap(cfg_tight, lp, x, ctx))(lp, x)

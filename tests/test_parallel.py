@@ -14,6 +14,7 @@ SUBPROCESS_TEMPLATE = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import AxisType
 {body}
 """
 
@@ -30,8 +31,7 @@ def run_with_devices(body: str):
 def test_pipeline_matches_sequential():
     out = run_with_devices(r"""
 from repro.parallel.pipeline import pipeline_forward, demo_stage_fn
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4,), ("pod",))
+mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(0)
 D, B, S = 8, 16, 4
 params = {"w": jnp.asarray(rng.standard_normal((S, D, D)), jnp.float32),
@@ -52,9 +52,8 @@ def test_compressed_psum_close_to_exact():
     out = run_with_devices(r"""
 from jax.sharding import PartitionSpec as P
 from repro.optim.compression import compressed_psum
-from repro.launch.mesh import make_mesh_compat
 from repro.parallel.sharding import shard_map_compat
-mesh = make_mesh_compat((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.standard_normal((4, 64)), jnp.float32)
 f = shard_map_compat(lambda v: compressed_psum(v[0], "data"), mesh=mesh,
@@ -73,8 +72,8 @@ def test_gnn_sharded_segment_sum_matches_local():
     out = run_with_devices(r"""
 from repro.models.gnn import _sharded_segment_reduce
 from repro.parallel.sharding import ShardingCtx
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 1), ("data", "model"))
+mesh = jax.make_mesh((4, 1), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 m, n, d = 64, 10, 5
 x = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)

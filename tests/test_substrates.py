@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.checkpoint.checkpoint import (CheckpointManager, latest_step,
                                          restore_checkpoint, save_checkpoint)
@@ -117,13 +118,12 @@ def test_schedule_warmup_and_decay():
 # ---------------------------------------------------------------- sharding --
 
 def _mesh22():
-    from repro.launch.mesh import make_mesh_compat
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_logical_to_spec_divisibility_fallback():
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = _mesh22()
     # sizes divide trivially on a 1x1 mesh
     spec = logical_to_spec(("batch", "embed"), (8, 16), mesh)
     assert spec is not None
@@ -131,8 +131,7 @@ def test_logical_to_spec_divisibility_fallback():
 
 def test_zero1_spec_adds_data_axis():
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = _mesh22()
     sp = zero1_spec(P(None, "model"), (16, 32), mesh)
     assert sp[0] in ("data", ("data",)) or sp[0] is None  # 16 % 1 == 0
 
