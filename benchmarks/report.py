@@ -145,8 +145,6 @@ def bench_trend(bench_dir: str = "."):
             "occupancy": co.get("occupancy"),
             "deadline_misses": co.get("deadline_misses"),
             "cache_ns_per_query": s.get("cache", {}).get("ns_per_query"),
-            "obs_overhead_frac": s.get("obs_overhead", {})
-                                  .get("traced_overhead_frac"),
         }
     dy = (arts["dynamic"]["data"] or {})
     for name, e in dy.get("datasets", {}).items():
@@ -203,9 +201,7 @@ def bench_table(bench_dir: str = ".") -> str:
                   f"| open-loop ns/query | {_fmt(s['open_ns_per_query'])} |",
                   f"| occupancy | {_fmt(s['occupancy'], '.3f')} |",
                   f"| deadline misses | {_fmt(s['deadline_misses'], '.0f')} |",
-                  f"| cache-hot ns/query | {_fmt(s['cache_ns_per_query'])} |",
-                  f"| obs traced overhead | "
-                  f"{_fmt(s['obs_overhead_frac'], '.4f')} |"]
+                  f"| cache-hot ns/query | {_fmt(s['cache_ns_per_query'])} |"]
     if t["dynamic"]:
         lines += ["", "### Dynamic updates", "",
                   "| dataset | metric | ns/query |", "|---|---|---|"]

@@ -263,68 +263,12 @@ def run_bench_json(out_path: str = "BENCH_serve.json",
          f"hit_rate={out['cache'].get('hit_rate', 0.0):.3f};"
          f"short_circuits={out['cache']['short_circuits']}")
 
-    out["obs_overhead"] = run_obs_overhead(dataset=dataset,
-                                           prebuilt=(g, ix, spec))
     from ._bench_schema import attach_envelope
     attach_envelope(out, bench="serve")
     with open(out_path, "w") as f:
         json.dump(out, f, indent=1)
     print(f"wrote {out_path}", flush=True)
     return out
-
-
-def run_obs_overhead(dataset: str = "go-like", n_queries: int | None = None,
-                     k: int = 2, seed: int = 0, prebuilt=None):
-    """A/B the telemetry layer's no-op path (ISSUE acceptance: tracer
-    disabled must cost < 1% closed-loop serving throughput).
-
-    Three closed-loop passes over the same warmed session/workload:
-    ``baseline`` (tracer disabled — every ``span()`` is one flag check),
-    repeated as ``baseline2`` (run-to-run noise floor), then ``traced``
-    (spans recorded). Reported ratios are against the better baseline
-    pass so scheduler jitter doesn't masquerade as obs overhead.
-    """
-    from repro import obs
-    from repro.core.workload import random_queries
-    from repro.reach import IndexSpec, QuerySession, build
-    n_queries = n_queries or (20_000 if quick_mode() else 100_000)
-    if prebuilt is not None:
-        g, ix, spec = prebuilt
-    else:
-        g = get_graph(dataset)
-        spec = IndexSpec(k=k, variant="G", phase2_mode="auto")
-        ix = build(g, spec)
-    qs, qt = random_queries(g, n_queries, seed=seed + 23)
-    sess = QuerySession(ix, spec)
-    sess.query(qs[:256], qt[:256])
-    sess.warmup(min(n_queries, spec.max_batch), n_queries % spec.max_batch)
-
-    def _pass():
-        sess.reset_stats()
-        with Timer() as t:
-            sess.query(qs, qt)
-        return t.seconds / n_queries * 1e9
-
-    obs.enable_tracing(False)
-    base_a = _pass()
-    base_b = _pass()
-    obs.enable_tracing(True)
-    try:
-        traced = _pass()
-    finally:
-        obs.enable_tracing(False)
-        obs.get_tracer().clear()
-    base = min(base_a, base_b)
-    rec = {"n_queries": n_queries,
-           "baseline_ns_per_query": base_a,
-           "baseline2_ns_per_query": base_b,
-           "traced_ns_per_query": traced,
-           "noop_rel_spread": abs(base_a - base_b) / base,
-           "traced_overhead_frac": (traced - base) / base}
-    emit(f"serve/{dataset}/obs-overhead",
-         rec["traced_overhead_frac"] * 100.0,
-         f"base={base:.0f}ns;traced={traced:.0f}ns")
-    return rec
 
 
 def main():

@@ -53,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops
-from ..obs import get_tracer, register_stats, span
+from ..obs import register_stats, span
 from .ferrari import FerrariIndex
 from .packed import PackedIndex, pack_index
 from .query import QueryEngine, ResettableStats
@@ -164,9 +164,13 @@ class DeviceQueryEngine:
         self._union_adj_cache = None  # (version, adj, crt) — dense mode
         # One jitted phase-1 executor per engine: its compile cache is keyed
         # by batch shape, so _cache_size() counts traces — the serving
-        # session asserts this stays at one per padding bucket.
-        self._classify_exec = jax.jit(
-            partial(ops.classify_queries, use_pallas=use_pallas))
+        # session asserts this stays at one per padding bucket. A named
+        # function, so its device ops read jit_phase1_classify/... in a
+        # profile.
+        def phase1_classify(dev, cs, ct):
+            return ops.classify_queries(dev, cs, ct, use_pallas=use_pallas)
+
+        self._classify_exec = jax.jit(phase1_classify)
 
     # ------------------------------------------------------ lazy structures
     @property
@@ -204,9 +208,13 @@ class DeviceQueryEngine:
         return self._classify_exec._cache_size()
 
     def classify(self, srcs, dsts):
-        cs = self.comp[jnp.asarray(srcs)]
-        ct = self.comp[jnp.asarray(dsts)]
-        verdict = self._classify_exec(self.dev, cs, ct)
+        with span("dispatch.h2d"):
+            srcs, dsts = jnp.asarray(srcs), jnp.asarray(dsts)
+        with span("dispatch.gather"):
+            cs = self.comp[srcs]
+            ct = self.comp[dsts]
+        with span("dispatch.classify"):
+            verdict = self._classify_exec(self.dev, cs, ct)
         return verdict, cs, ct
 
     def stage_queries(self, srcs, dsts):
@@ -258,42 +266,51 @@ class DeviceQueryEngine:
         future, so the caller can overlap host work (staging the NEXT
         batch's host→device transfer — see ``QuerySession.begin``/
         ``finish`` and the frontend's double-buffered slabs) against the
-        classify compute before calling ``finish_answer``.
+        classify compute before calling ``finish_answer``. The
+        ``dispatch`` span covers it, on ``query()``'s path and the staged
+        one alike.
         """
-        return self.classify(srcs, dsts)
+        with span("dispatch", bucket=len(srcs)):
+            return self.classify(srcs, dsts)
 
     def finish_answer(self, handle) -> np.ndarray:
         """Block on a ``start_answer`` handle and run phase 2 on the
         UNKNOWN residue. ``answer()`` is exactly start + finish.
 
         The ``phase1`` span covers blocking on the classify verdict (i.e.
-        the device compute start_answer dispatched) plus the residue
-        bookkeeping; ``phase2`` covers the residue driver. Their
+        the device compute start_answer dispatched and the copy back,
+        ``phase1.wait``) plus the residue bookkeeping (``phase1.tally``);
+        ``phase2`` covers the residue driver. Their
         wall-clock also lands in ``last_phase1_s``/``last_phase2_s``
         regardless of tracing (the frontend's slow-slab log reads them)."""
         verdict, cs, ct = handle
         t0 = time.perf_counter()
         with span("phase1", q=int(verdict.shape[0])):
-            verdict = np.asarray(verdict)
-            out = verdict == ops.POS
-            neg_mask = verdict == ops.NEG
-            unknown = np.flatnonzero(verdict == ops.UNKNOWN)
-            self.stats.n_queries += len(verdict)
-            self.stats.phase1_pos += int(out.sum())
-            overlay = self._overlay_live
-            if overlay:
-                # base-NEG is no longer final when the source can reach a
-                # delta tail: those queries join the union-graph expansion
-                # (and leave the phase-1 mix — phase1_pos/neg/
-                # phase2_queries stay a partition of n_queries under churn)
-                reopened = np.flatnonzero(
-                    neg_mask & self.overlay.can_reach_tail[np.asarray(cs)])
-                residue = np.union1d(unknown, reopened)
-                self.stats.phase1_neg += int(neg_mask.sum()) - reopened.size
-            else:
-                residue = unknown
-                self.stats.phase1_neg += int(neg_mask.sum())
-            self.stats.phase2_queries += residue.size
+            with span("phase1.wait"):
+                verdict = np.asarray(verdict)
+            with span("phase1.tally"):
+                out = verdict == ops.POS
+                neg_mask = verdict == ops.NEG
+                unknown = np.flatnonzero(verdict == ops.UNKNOWN)
+                self.stats.n_queries += len(verdict)
+                self.stats.phase1_pos += int(out.sum())
+                overlay = self._overlay_live
+                if overlay:
+                    # base-NEG is no longer final when the source can reach
+                    # a delta tail: those queries join the union-graph
+                    # expansion (and leave the phase-1 mix — phase1_pos/
+                    # neg/phase2_queries stay a partition of n_queries
+                    # under churn)
+                    reopened = np.flatnonzero(
+                        neg_mask
+                        & self.overlay.can_reach_tail[np.asarray(cs)])
+                    residue = np.union1d(unknown, reopened)
+                    self.stats.phase1_neg += (int(neg_mask.sum())
+                                              - reopened.size)
+                else:
+                    residue = unknown
+                    self.stats.phase1_neg += int(neg_mask.sum())
+                self.stats.phase2_queries += residue.size
         t1 = time.perf_counter()
         self.last_phase1_s = t1 - t0
         self.last_phase2_s = 0.0
@@ -350,14 +367,15 @@ class DeviceQueryEngine:
             ct_h = np.zeros(chunk, dtype=np.int32)
             cs_h[:q] = cs_u[lo:hi]
             ct_h[:q] = ct_u[lo:hi]
-            cs = jnp.asarray(cs_h)
-            ct = jnp.asarray(ct_h)
-            expandable, definite_pos = ops.classify_all_nodes_vs_target(
-                self.dev, ct, can_reach_tail=can_reach_tail)
-            front0 = jax.nn.one_hot(cs, n, dtype=jnp.bool_)
-            pos = _dense_bfs(front0, expandable, definite_pos,
-                             adj, max_steps)
-            res[lo:hi] = np.asarray(pos)[:q]
+            with span("phase2.chunk", q=q):
+                cs = jnp.asarray(cs_h)
+                ct = jnp.asarray(ct_h)
+                expandable, definite_pos = ops.classify_all_nodes_vs_target(
+                    self.dev, ct, can_reach_tail=can_reach_tail)
+                front0 = jax.nn.one_hot(cs, n, dtype=jnp.bool_)
+                pos = _dense_bfs(front0, expandable, definite_pos,
+                                 adj, max_steps)
+                res[lo:hi] = np.asarray(pos)[:q]
         return res
 
     def _phase2_dense(self, cs_u: np.ndarray, ct_u: np.ndarray) -> np.ndarray:
@@ -424,31 +442,35 @@ class DeviceQueryEngine:
             ct[:q] = ct_u[lo:hi]
             pad = np.ones(chunk, bool)
             pad[:q] = False
-            cs_j, ct_j = jnp.asarray(cs), jnp.asarray(ct)
             cap = max(self.frontier_cap, chunk)
             pos = np.zeros(chunk, bool)
-            while True:
-                p, ovf = expand_fn(cs_j, ct_j, pad, cap)
-                pos |= p
-                if not ovf:
-                    break
-                # overflow: POS answers are sound, only non-positives need
-                # the retry — mask them out and rerun with 4x the capacity
-                cap *= 4
-                self.stats.sparse_retries += 1
-                get_tracer().instant("phase2.overflow_retry", cap=cap)
-                if cap > self.frontier_cap_max:
-                    unresolved = np.flatnonzero(~pos & ~pad)
-                    self.stats.phase2_host += unresolved.size
-                    self.stats.phase2_sparse -= unresolved.size
-                    with span("phase2.host_fallback",
-                              q=int(unresolved.size)):
-                        pos[unresolved] = host_fn(cs[unresolved],
-                                                  ct[unresolved])
-                    break
-                pad = pad | pos
-                if pad.all():
-                    break       # every live query already proved positive
+            retries = 0
+            with span("phase2.chunk", q=q, cap=cap) as sp:
+                cs_j, ct_j = jnp.asarray(cs), jnp.asarray(ct)
+                while True:
+                    p, ovf = expand_fn(cs_j, ct_j, pad, cap)
+                    pos |= p
+                    if not ovf:
+                        break
+                    # overflow: POS answers are sound, only non-positives
+                    # need the retry — mask them out and rerun with 4x the
+                    # capacity
+                    cap *= 4
+                    retries += 1
+                    if cap > self.frontier_cap_max:
+                        unresolved = np.flatnonzero(~pos & ~pad)
+                        self.stats.phase2_host += unresolved.size
+                        self.stats.phase2_sparse -= unresolved.size
+                        with span("phase2.host_fallback",
+                                  q=int(unresolved.size)):
+                            pos[unresolved] = host_fn(cs[unresolved],
+                                                      ct[unresolved])
+                        break
+                    pad = pad | pos
+                    if pad.all():
+                        break   # every live query already proved positive
+                sp.set(retries=retries)
+            self.stats.sparse_retries += retries
             res[lo:hi] = pos[:q]
         if perm is not None:
             out = np.empty_like(res)

@@ -92,8 +92,9 @@ def _classify_emit_kernel(meta_s_ref, meta_t_ref, slab_ref, key_ref, eq_ref,
                                jnp.int32(2**31 - 1))
 
 
-def _row_call(kernel, args, *, block, interpret):
-    """Grid a lane-wise kernel over 1-D int32 operands of equal length."""
+def _row_call(kernel, args, *, block, interpret, name):
+    """Grid a lane-wise kernel over 1-D int32 operands of equal length;
+    ``name`` is the kernel's op name in a device profile."""
     c = args[0].shape[0]
     cp = -(-c // block) * block
     padded = [jnp.pad(a, (0, cp - c))[None, :] for a in args]
@@ -105,6 +106,7 @@ def _row_call(kernel, args, *, block, interpret):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((1, cp), jnp.int32),
         interpret=interpret,
+        name=name,
     )(*padded)
     return out[0, :c]
 
@@ -172,7 +174,7 @@ def expand_frontier_loop_fused(ell, tail_src, tail_dst, is_hub, cs, ct,
                 (cq, cv, ok.astype(jnp.int32),
                  visited[cq, cv >> 5].view(jnp.int32),
                  pos[cq].astype(jnp.int32)),
-                block=block, interpret=interpret)
+                block=block, interpret=interpret, name="frontier_probe")
             # O(C) compaction into cap+1 slots, then a SMALL unique for
             # within-step duplicates; raw > cap+1 is conservative overflow
             emit = keys != SENTINEL
@@ -272,6 +274,7 @@ def _classify_call(meta_s, meta_t, slab_s, keys, eq, *, block, interpret):
         out_specs=[row, row],
         out_shape=[jax.ShapeDtypeStruct((1, cp), jnp.int32)] * 2,
         interpret=interpret,
+        name="frontier_classify_emit",
     )(*args)
     return verdict[0, :c], front[0, :c]
 

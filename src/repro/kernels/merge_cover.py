@@ -208,5 +208,6 @@ def merge_cover_sorted_rows(cb, ce, cx, *, k: int, w_out: int,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes(m, block_b)),
         interpret=interpret,
+        name="merge_cover",
     )(*args)
     return nb.T[:B], ne.T[:B], nx.T[:B] != 0, cnt[0, :B]

@@ -1,31 +1,38 @@
 """Trace spans: low-overhead recorder + Chrome trace-event export
-(DESIGN.md §8.3).
+(DESIGN.md §8.2).
 
-Two recording APIs over one ring buffer:
+Three recording APIs over one ring buffer:
 
   * ``span(name, **attrs)`` — context manager; nests through a
     contextvar stack, so ``with span("finish"): with span("phase2"): ...``
-    records phase2 with finish as its parent. When jax is importable,
-    enabled context-manager spans also enter
-    ``jax.profiler.TraceAnnotation`` (or ``StepTraceAnnotation`` when a
-    ``step=`` attr is given), so device profiles captured with
-    ``jax.profiler.trace`` line up with these host spans.
+    records phase2 with finish as its parent. Attributes known only at
+    the span's end go in through ``set(**attrs)``.
   * ``begin_span(name, parent=..., track=..., **attrs)`` /
-    ``end_span(token)`` — explicit pair for spans whose lifetime crosses
-    call boundaries, i.e. the double-buffered serving path where slab
-    N+1's staging span OVERLAPS slab N's classify span. Explicit spans
-    take only the parent they are handed (default: none) — they never
-    adopt the ambient context-manager stack, so slab N+1's staging can
-    never parent into slab N's in-flight spans. They also skip jax
-    annotations: TraceMe demands strict per-thread nesting, which
-    interleaved slabs violate by design.
+    ``end_span(token, **attrs)`` — explicit pair for spans whose lifetime
+    crosses call boundaries: a request's queue wait (submit to slab cut),
+    and the double-buffered serving path where slab N+1's staging span
+    OVERLAPS slab N's classify span. Explicit spans take only the parent
+    they are handed (default: none) — they never adopt the ambient
+    context-manager stack, so slab N+1's staging can never parent into
+    slab N's in-flight spans.
+  * ``instant(name, **attrs)`` — a zero-duration marker.
+
+While tracing is on, every span also enters a
+``jax.profiler.TraceAnnotation`` of its name (when jax is importable), so
+a profile captured with ``jax.profiler`` holds the same spans on the
+device trace's clock: one constant offset separates the ring's
+``perf_counter`` times from the profile's. Annotations need no strict
+nesting — an explicit span may end after a later one began, as the
+double-buffered slabs do. A ``TraceAnnotation`` takes its start time
+when it is constructed, so each is constructed where its span begins.
 
 Tracing is DISABLED by default: ``span()`` then returns a shared no-op
-context manager and ``begin_span`` returns ``None`` — one flag check on
-the hot path (measured in ``benchmarks/serving_perf.py`` ``obs_overhead``;
-budget <1%, DESIGN.md §8.5). Enable with ``enable_tracing()`` (or
-``serve.py --trace-out``), export with ``export_chrome_trace(path)`` and
-load the file at https://ui.perfetto.dev.
+context manager, ``begin_span`` returns ``None`` and ``instant`` returns
+— one flag check, no clock read and no allocation in the tracer. Its
+cost when on is measured on the chip as traced against untraced runs of
+the benchmark's cells (DESIGN.md §8.5). Enable with ``enable_tracing()``
+(or ``serve.py --trace-out``), export with ``export_chrome_trace(path)``
+and load the file at https://ui.perfetto.dev.
 """
 from __future__ import annotations
 
@@ -44,16 +51,18 @@ _DEFAULT_CAPACITY = 1 << 16
 class SpanToken:
     """Handle for an explicit begin/end span (and test introspection)."""
 
-    __slots__ = ("id", "name", "t0", "parent", "track", "attrs")
+    __slots__ = ("id", "name", "t0", "parent", "track", "attrs", "anno")
 
     def __init__(self, id: int, name: str, t0: float,
-                 parent: Optional[int], track: Optional[str], attrs: dict):
+                 parent: Optional[int], track: Optional[str], attrs: dict,
+                 anno):
         self.id = id
         self.name = name
         self.t0 = t0
         self.parent = parent
         self.track = track
         self.attrs = attrs
+        self.anno = anno
 
 
 class _NoopSpan:
@@ -68,6 +77,9 @@ class _NoopSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **attrs) -> None:
+        pass
 
 
 _NOOP = _NoopSpan()
@@ -93,24 +105,14 @@ class _LiveSpan:
         tr = self._tr
         stack = tr._stack.get()
         self._parent_tok = tr._stack.set(stack + (self.id,))
-        anno = tr._annotation(self.name, self.attrs)
-        if anno is not None:
-            try:
-                anno.__enter__()
-                self._anno = anno
-            except Exception:       # profiler backend unavailable mid-run
-                self._anno = None
+        self._anno = tr._open_annotation(self.name)
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         self.dur = t1 - self.t0
-        if self._anno is not None:
-            try:
-                self._anno.__exit__(*exc)
-            except Exception:
-                pass
+        _close_annotation(self._anno, exc)
         tr = self._tr
         stack = tr._stack.get()
         parent = stack[-2] if len(stack) >= 2 else None
@@ -118,6 +120,18 @@ class _LiveSpan:
         tr._record(self.name, self.t0, self.dur, self.id, parent,
                    None, self.attrs)
         return False
+
+    def set(self, **attrs) -> None:
+        """Attach attributes known only once the span's work has run."""
+        self.attrs.update(attrs)
+
+
+def _close_annotation(anno, exc=(None, None, None)) -> None:
+    if anno is not None:
+        try:
+            anno.__exit__(*exc)
+        except Exception:       # profiler backend unavailable mid-run
+            pass
 
 
 class Tracer:
@@ -146,8 +160,9 @@ class Tracer:
         double-buffered path hands parents around by token instead."""
         if not self.enabled:
             return None
+        anno = self._open_annotation(name)
         return SpanToken(next(self._ids), name, time.perf_counter(),
-                         parent, track, attrs)
+                         parent, track, attrs, anno)
 
     def end(self, token: Optional[SpanToken],
             **extra_attrs) -> Optional[float]:
@@ -157,30 +172,20 @@ class Tracer:
         if token is None:
             return None
         dur = time.perf_counter() - token.t0
+        _close_annotation(token.anno)
         attrs = {**token.attrs, **extra_attrs} if extra_attrs else token.attrs
         self._record(token.name, token.t0, dur, token.id, token.parent,
                      token.track, attrs)
         return dur
 
-    def record(self, name: str, t0: float, dur: float, *,
-               parent: Optional[int] = None, track: Optional[str] = None,
-               **attrs) -> Optional[int]:
-        """Record a span retroactively from timestamps the caller already
-        holds (the frontend's queue-wait rides on its EWMA clock reads —
-        no extra clock calls, no token to carry). ``t0`` must be in the
-        ``time.perf_counter`` domain. Returns the span id."""
-        if not self.enabled:
-            return None
-        sid = next(self._ids)
-        self._record(name, t0, dur, sid, parent, track, attrs)
-        return sid
-
     def instant(self, name: str, **attrs) -> None:
         """Zero-duration marker event (deadline misses, drops...)."""
         if not self.enabled:
             return
-        self._record(name, time.perf_counter(), 0.0, next(self._ids),
-                     None, None, attrs)
+        anno = self._open_annotation(name)
+        t = time.perf_counter()
+        _close_annotation(anno)
+        self._record(name, t, 0.0, next(self._ids), None, None, attrs)
 
     def _record(self, name, t0, dur, id, parent, track, attrs) -> None:
         with self._lock:
@@ -191,21 +196,23 @@ class Tracer:
             self.n_recorded += 1
 
     # ----------------------------------------------------- jax annotations
-    def _annotation(self, name: str, attrs: dict):
+    def _open_annotation(self, name: str):
+        """A ``jax.profiler.TraceAnnotation`` named ``name``, constructed
+        and entered now (None without jax's profiler)."""
         if self._annotate is None:
             try:
-                from jax import profiler as _prof
-                self._annotate = (_prof.TraceAnnotation,
-                                  getattr(_prof, "StepTraceAnnotation", None))
-            except Exception:
-                self._annotate = (False, False)
-        anno, step_anno = self._annotate
-        if not anno:
+                from jax.profiler import TraceAnnotation
+                self._annotate = TraceAnnotation
+            except ImportError:
+                self._annotate = False
+        if not self._annotate:
             return None
-        step = attrs.get("step")
-        if step is not None and step_anno:
-            return step_anno(name, step_num=int(step))
-        return anno(name)
+        anno = self._annotate(name)
+        try:
+            anno.__enter__()
+        except Exception:       # profiler backend unavailable mid-run
+            return None
+        return anno
 
     # ------------------------------------------------------------ introspect
     @property

@@ -41,6 +41,7 @@ class _Cut:
     staged: object              # QuerySession._StagedBatch
     version: tuple              # graph version the slab is computed under
     q: int                      # real queries in the slab
+    seq: int                    # slab sequence number
     t_assemble: float = 0.0     # clock() when the slab was cut
     stage_s: float = 0.0        # host->device staging wall time
 
@@ -189,7 +190,8 @@ class Frontend:
             # every pair answered from the cache (or an empty request):
             # complete without touching a queue or the device
             if hit is not None:
-                self.cache.commit_probe(srcs, dsts, hit)
+                with span("cache_probe.commit"):
+                    self.cache.commit_probe(srcs, dsts, hit)
             self._next_ticket += 1
             acc["requests"] += 1
             acc["queries"] += n
@@ -201,9 +203,14 @@ class Frontend:
         req = Request(ticket=ticket, tenant=tenant, srcs=srcs, dsts=dsts,
                       t_submit=now, deadline=now + tq.deadline_s,
                       answers=answers, pending=pending)
-        self.router.admit(req)              # raises Rejected on backpressure
+        with span("coalesce.admit"):
+            self.router.admit(req)          # raises Rejected on backpressure
+        # explicit: the wait ends at the slab cut, in a later poll()
+        req.wait_span = get_tracer().begin(
+            "queue_wait", track="requests", ticket=ticket, tenant=tenant)
         if hit is not None:
-            self.cache.commit_probe(srcs, dsts, hit)
+            with span("cache_probe.commit"):
+                self.cache.commit_probe(srcs, dsts, hit)
         self._next_ticket += 1
         acc["requests"] += 1
         acc["queries"] += n
@@ -257,9 +264,8 @@ class Frontend:
             # parity track: it OVERLAPS the next slab's staging, so it
             # must neither use the implicit span stack nor share a track
             # with its neighbour (repro.obs.trace)
-            seq = self._n_batches
-            tok = get_tracer().begin("slab", track=f"slab-{seq % 2}",
-                                     slab=seq, q=cut.q)
+            tok = get_tracer().begin("slab", track=f"slab-{cut.seq % 2}",
+                                     slab=cut.seq, q=cut.q)
             # re-read the clock at dispatch: _finish() above may have
             # blocked on the previous slab, and the service EWMA must
             # measure THIS slab's begin->finish time, not the prior
@@ -297,27 +303,28 @@ class Frontend:
 
     # ------------------------------------------------------------ internals
     def _assemble(self, reason: str) -> None:
-        reqs = self.router.take_batch(self.batch_target)
-        if not reqs:
-            return
-        t_a = self.clock()
-        tr = get_tracer()
-        for r in reqs:
-            wait = max(0.0, t_a - r.t_submit)
-            self._h_queue_wait.observe(wait)
-            if tr.enabled:
-                # retroactive: the span is reconstructed from the submit
-                # timestamp the request already carries
-                tr.record("queue_wait", r.t_submit, wait, track="requests",
-                          ticket=r.ticket, tenant=r.tenant)
-        with span("coalesce", reason=reason, n_reqs=len(reqs)):
+        # the slab this cut becomes: the one in flight, if any, finishes
+        # in this poll() before it is dispatched
+        seq = self._n_batches + (self._inflight is not None)
+        with span("coalesce", reason=reason) as sp:
+            with span("coalesce.take"):
+                reqs = self.router.take_batch(self.batch_target)
+                t_a = self.clock()
+                tr = get_tracer()
+                for r in reqs:
+                    self._h_queue_wait.observe(max(0.0, t_a - r.t_submit))
+                    if r.wait_span is not None:
+                        tr.end(r.wait_span, slab=seq)
+            if not reqs:
+                return
+            sp.set(n_reqs=len(reqs))
             cat_s = np.concatenate([r.srcs[r.pending] for r in reqs])
             cat_t = np.concatenate([r.dsts[r.pending] for r in reqs])
             staged = self.session.stage(cat_s, cat_t)  # H2D starts
         stage_s = max(0.0, self.clock() - t_a)
         self._staged = _Cut(reqs=reqs, staged=staged,
                             version=self._graph_version(), q=cat_s.size,
-                            t_assemble=t_a, stage_s=stage_s)
+                            seq=seq, t_assemble=t_a, stage_s=stage_s)
         if reason == "deadline":
             self._deadline_flushes += 1
         elif reason == "full":
@@ -335,44 +342,45 @@ class Frontend:
         dt = max(0.0, now - t_begin)
         tr = get_tracer()
         tr.end(slab_tok)
-        self._h_service.observe(dt)
-        self._service_ewma = (dt if not self._ewma_primed
-                              else 0.7 * self._service_ewma + 0.3 * dt)
-        self._ewma_primed = True
-        misses = 0
-        lo = 0
-        for req in cut.reqs:
-            k = req.pending.size
-            sub = ans[lo: lo + k]
-            lo += k
-            req.answers[req.pending] = sub
-            if self.cache is not None:
-                # version-guarded: a slab that raced an update/compact
-                # must not seed the new graph's cache with old answers
-                self.cache.insert(cut.version, req.srcs[req.pending],
-                                  req.dsts[req.pending], sub)
-            self._completed[req.ticket] = req.answers
-            acc = self._acc[req.tenant]
-            acc["completed"] += 1
-            acc["lat"].add(now - req.t_submit)
-            if now > req.deadline:
-                acc["deadline_misses"] += 1
-                misses += 1
-                tr.instant("deadline_miss", ticket=req.ticket,
-                           tenant=req.tenant,
-                           late_us=(now - req.deadline) * 1e6)
-        eng = self.session.engine
-        self.slowlog.observe_slab(
-            slab=self._n_batches, service_s=dt, n_queries=cut.q,
-            deadline_misses=misses,
-            breakdown={"stage": cut.stage_s,
-                       "phase1": eng.last_phase1_s,
-                       "phase2": eng.last_phase2_s})
-        self._n_batches += 1
-        self._batch_queries += cut.q
-        self._batch_slots += cut.staged.bucket
-        b = _pow2ceil(max(cut.q, 1))
-        self._occupancy_hist[b] = self._occupancy_hist.get(b, 0) + 1
+        with span("finish.deliver", q=cut.q, n_reqs=len(cut.reqs)):
+            self._h_service.observe(dt)
+            self._service_ewma = (dt if not self._ewma_primed
+                                  else 0.7 * self._service_ewma + 0.3 * dt)
+            self._ewma_primed = True
+            misses = 0
+            lo = 0
+            for req in cut.reqs:
+                k = req.pending.size
+                sub = ans[lo: lo + k]
+                lo += k
+                req.answers[req.pending] = sub
+                if self.cache is not None:
+                    # version-guarded: a slab that raced an update/compact
+                    # must not seed the new graph's cache with old answers
+                    self.cache.insert(cut.version, req.srcs[req.pending],
+                                      req.dsts[req.pending], sub)
+                self._completed[req.ticket] = req.answers
+                acc = self._acc[req.tenant]
+                acc["completed"] += 1
+                acc["lat"].add(now - req.t_submit)
+                if now > req.deadline:
+                    acc["deadline_misses"] += 1
+                    misses += 1
+                    tr.instant("deadline_miss", ticket=req.ticket,
+                               tenant=req.tenant,
+                               late_us=(now - req.deadline) * 1e6)
+            eng = self.session.engine
+            self.slowlog.observe_slab(
+                slab=self._n_batches, service_s=dt, n_queries=cut.q,
+                deadline_misses=misses,
+                breakdown={"stage": cut.stage_s,
+                           "phase1": eng.last_phase1_s,
+                           "phase2": eng.last_phase2_s})
+            self._n_batches += 1
+            self._batch_queries += cut.q
+            self._batch_slots += cut.staged.bucket
+            b = _pow2ceil(max(cut.q, 1))
+            self._occupancy_hist[b] = self._occupancy_hist.get(b, 0) + 1
         return len(cut.reqs)
 
     # ---------------------------------------------------------- live graph

@@ -25,6 +25,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ...obs.trace import SpanToken
+
 REJECT_REASONS = ("too_large", "queue_full")
 
 
@@ -49,6 +51,7 @@ class Request:
     deadline: float             # t_submit + tenant deadline
     answers: np.ndarray         # [n] bool; cache hits pre-filled at submit
     pending: np.ndarray         # indices still needing the device (misses)
+    wait_span: Optional[SpanToken] = None   # queue_wait, submit->slab cut
 
 
 @dataclass

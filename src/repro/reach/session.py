@@ -70,6 +70,15 @@ class SessionStats(ResettableStats):
         return d
 
 
+def _pad(srcs: np.ndarray, dsts: np.ndarray, b: int):
+    """Pad a batch to bucket ``b`` with (0, 0) self-queries."""
+    ps = np.zeros(b, dtype=np.int64)
+    pt = np.zeros(b, dtype=np.int64)
+    ps[:srcs.size] = srcs
+    pt[:dsts.size] = dsts
+    return ps, pt
+
+
 @dataclass
 class _StagedBatch:
     """A padded batch whose host→device transfer is in flight."""
@@ -183,10 +192,8 @@ class QuerySession:
         q = s.size
         b = self._bucket(q)
         if q < b:
-            ps = np.zeros(b, dtype=np.int64)
-            pt = np.zeros(b, dtype=np.int64)
-            ps[:q] = s
-            pt[:q] = t
+            with span("stage.pad", q=q, bucket=b):
+                ps, pt = _pad(s, t, b)
             ans = self.engine.answer(ps, pt)[:q]
             self._n_padded += b - q
         else:
@@ -248,15 +255,11 @@ class QuerySession:
             raise ValueError(f"staged batch of {q} exceeds max_batch="
                              f"{self.spec.max_batch}; chop it first")
         b = self._bucket(max(q, 1))
-        if q < b:
-            ps = np.zeros(b, dtype=np.int64)
-            pt = np.zeros(b, dtype=np.int64)
-            ps[:q] = srcs
-            pt[:q] = dsts
-        else:
-            ps, pt = srcs, dsts
         with span("stage", q=q, bucket=b):
-            cs, ct = self.engine.stage_queries(ps, pt)
+            if q < b:
+                with span("stage.pad"):
+                    srcs, dsts = _pad(srcs, dsts, b)
+            cs, ct = self.engine.stage_queries(srcs, dsts)
         return _StagedBatch(q=q, bucket=b, srcs=cs, dsts=ct)
 
     def begin(self, staged: "_StagedBatch") -> "_InflightBatch":
@@ -264,8 +267,7 @@ class QuerySession:
         handle is bound to the CURRENT engine: ``compact()`` refuses to
         run while any handle is outstanding (see there)."""
         t0 = time.perf_counter()
-        with span("dispatch", bucket=staged.bucket):
-            handle = self.engine.start_answer(staged.srcs, staged.dsts)
+        handle = self.engine.start_answer(staged.srcs, staged.dsts)
         self._n_inflight += 1
         return _InflightBatch(staged=staged, handle=handle, t0=t0)
 

@@ -237,13 +237,105 @@ def test_ring_capacity_and_drop_count():
     assert [e["name"] for e in tr.events()] == ["e6", "e7", "e8", "e9"]
 
 
-def test_record_retroactive_span():
+def test_span_set_attaches_attrs_at_exit():
     tr = Tracer()
     tr.enabled = True
-    sid = tr.record("queue_wait", 1.0, 0.5, track="requests", ticket=7)
-    ev = tr.events()[0]
-    assert ev["id"] == sid and ev["dur"] == 0.5
-    assert ev["track"] == "requests" and ev["args"]["ticket"] == 7
+    with tr.span("phase2.chunk", q=4) as sp:
+        sp.set(retries=2)
+    assert tr.events()[0]["args"] == {"q": 4, "retries": 2}
+    tr.enabled = False
+    with tr.span("off") as sp:
+        sp.set(retries=1)                 # the shared no-op takes it too
+    assert len(tr.events()) == 1
+
+
+class _CountingAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: counts constructions
+    and checks every entered annotation is exited once."""
+    made = []
+
+    def __init__(self, name):
+        self.name = name
+        self.state = "made"
+        _CountingAnnotation.made.append(self)
+
+    def __enter__(self):
+        assert self.state == "made"
+        self.state = "open"
+
+    def __exit__(self, *exc):
+        assert self.state == "open"
+        self.state = "closed"
+
+
+def _drive(tr):
+    with tr.span("ctx"):
+        tok = tr.begin("explicit")
+    tr.end(tok)
+    tr.instant("mark")
+
+
+def test_disabled_tracing_opens_no_annotation():
+    tr = Tracer()
+    tr._annotate = _CountingAnnotation
+    _CountingAnnotation.made = []
+    _drive(tr)
+    assert _CountingAnnotation.made == [] and tr.events() == []
+    tr.enabled = True
+    _drive(tr)
+    assert [a.name for a in _CountingAnnotation.made] == \
+        ["ctx", "explicit", "mark"]
+    assert all(a.state == "closed" for a in _CountingAnnotation.made)
+
+
+def test_spans_share_the_profilers_clock(tmp_path):
+    """Context spans, explicit spans that interleave (A opens, B opens, A
+    closes, B closes) and an instant all land in the jax profile, each
+    at the ring's times plus one constant offset."""
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    tr.enabled = True
+    gap = 0.02
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tr.span("obs.outer"):
+            time.sleep(gap)
+            with tr.span("obs.inner"):
+                time.sleep(gap)
+        a = tr.begin("obs.a", track="slab-0")
+        time.sleep(gap)
+        b = tr.begin("obs.b", track="slab-1")
+        time.sleep(gap)
+        tr.end(a)
+        time.sleep(gap)
+        tr.end(b)
+        time.sleep(gap)
+        tr.instant("obs.mark")
+    finally:
+        jax.profiler.stop_trace()
+    [path] = list(tmp_path.glob("**/*.xplane.pb"))
+    prof = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("obs."):
+                        prof[e.name] = (e.start_ns * 1e-9, e.end_ns * 1e-9)
+    ring = {e["name"]: (e["ts"], e["ts"] + e["dur"]) for e in tr.events()}
+    assert set(prof) == set(ring) == {"obs.outer", "obs.inner", "obs.a",
+                                      "obs.b", "obs.mark"}
+    offsets = [p - r for name in ring
+               for p, r in zip(prof[name], ring[name])]
+    # a misplaced start or end would be off by a multiple of the gap
+    assert max(offsets) - min(offsets) < gap / 4, offsets
+    assert prof["obs.a"][0] < prof["obs.b"][0] < prof["obs.a"][1] \
+        < prof["obs.b"][1]
 
 
 def test_chrome_trace_tracks_map_to_tids(tmp_path):

@@ -73,9 +73,14 @@ def smoke():
     return mod
 
 
-def _compile(fn, *shapes):
+def _compile(fn, *shapes, kernel=None):
+    """Compile for the described chip; ``kernel`` is the op name the
+    Pallas call must carry into a device profile."""
     compiled = jax.jit(fn).lower(*shapes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if kernel is not None:
+        assert f"%{kernel}." in text, kernel
     return compiled
 
 
@@ -85,7 +90,8 @@ def test_stab_packed_compiles(one_chip, k2):
     meta = jax.ShapeDtypeStruct((q, 4), jnp.int32, sharding=one_chip)
     slab = jax.ShapeDtypeStruct((q, k2), jnp.int32, sharding=one_chip)
     _compile(lambda ms, mt, s: interval_stab_classify_packed(
-        ms, mt, s, block_q=DEFAULT_BLOCK_Q), meta, meta, slab)
+        ms, mt, s, block_q=DEFAULT_BLOCK_Q), meta, meta, slab,
+        kernel="interval_stab_classify_packed")
 
 
 def test_stab_12_array_compiles(one_chip):
@@ -106,8 +112,8 @@ def test_frontier_probe_compiles(one_chip):
     lane = jax.ShapeDtypeStruct((c,), jnp.int32, sharding=one_chip)
     probe = lambda *r: _probe_kernel(*r, vbits=22)  # noqa: E731
     _compile(lambda *a: _row_call(probe, a, block=PROBE_BLOCK,
-                                  interpret=False),
-             lane, lane, lane, lane, lane)
+                                  interpret=False, name="frontier_probe"),
+             lane, lane, lane, lane, lane, kernel="frontier_probe")
 
 
 def test_frontier_classify_emit_compiles(one_chip):
@@ -118,7 +124,7 @@ def test_frontier_classify_emit_compiles(one_chip):
     _compile(lambda ms, mt, s, key, eq: _classify_call(
         ms, mt, s, key, eq, block=PROBE_BLOCK, interpret=False),
         sds((c, 4)), sds((c, 4)), sds((c, 16)), sds((c,)),
-        sds((c,), jnp.bool_))
+        sds((c,), jnp.bool_), kernel="frontier_classify_emit")
 
 
 @pytest.mark.parametrize("m", [17, 2049])
@@ -127,7 +133,7 @@ def test_merge_cover_compiles(one_chip, m):
     tree interval) at the default block of 128 rows."""
     s = jax.ShapeDtypeStruct((1024, m), jnp.int32, sharding=one_chip)
     compiled = _compile(lambda b, e, x: merge_cover_sorted_rows(
-        b, e, x, k=8, w_out=8), s, s, s)
+        b, e, x, k=8, w_out=8), s, s, s, kernel="merge_cover")
     print(f"merge_cover m={m}: {compiled.memory_analysis()}")
 
 
@@ -146,7 +152,7 @@ def test_fused_frontier_loop_compiles(one_chip, smoke):
             pk, ell, ts, td, hub, cs, ct, pad, max_steps=smoke.LAYERS + 1,
             cap=4096, interpret=False),
         packed, sds((n, w)), sds((m_t,)), sds((m_t,)), sds((n,), jnp.bool_),
-        sds((q,)), sds((q,)), sds((q,), jnp.bool_))
+        sds((q,)), sds((q,)), sds((q,), jnp.bool_), kernel="frontier_probe")
     print(f"expand_frontier_fused n={n}: {compiled.memory_analysis()}")
 
 
