@@ -164,11 +164,16 @@ class DeviceQueryEngine:
         self._union_adj_cache = None  # (version, adj, crt) — dense mode
         # One jitted phase-1 executor per engine: its compile cache is keyed
         # by batch shape, so _cache_size() counts traces — the serving
-        # session asserts this stays at one per padding bucket. A named
-        # function, so its device ops read jit_phase1_classify/... in a
-        # profile.
-        def phase1_classify(dev, cs, ct):
-            return ops.classify_queries(dev, cs, ct, use_pallas=use_pallas)
+        # session asserts this stays at one per padding bucket. It takes
+        # original node ids and looks up their components itself, so a
+        # batch is one compiled program. The table is an argument, as the
+        # slab is: as a closed-over constant it would be baked into the
+        # program text. A named function, so its device ops read
+        # jit_phase1_classify/... in a profile.
+        def phase1_classify(dev, comp, srcs, dsts):
+            cs, ct = comp[srcs], comp[dsts]
+            verdict = ops.classify_queries(dev, cs, ct, use_pallas=use_pallas)
+            return verdict, cs, ct
 
         self._classify_exec = jax.jit(phase1_classify)
 
@@ -210,12 +215,8 @@ class DeviceQueryEngine:
     def classify(self, srcs, dsts):
         with span("dispatch.h2d"):
             srcs, dsts = jnp.asarray(srcs), jnp.asarray(dsts)
-        with span("dispatch.gather"):
-            cs = self.comp[srcs]
-            ct = self.comp[dsts]
         with span("dispatch.classify"):
-            verdict = self._classify_exec(self.dev, cs, ct)
-        return verdict, cs, ct
+            return self._classify_exec(self.dev, self.comp, srcs, dsts)
 
     def stage_queries(self, srcs, dsts):
         """Start the host→device transfer of a query batch (asynchronous)
