@@ -1,19 +1,31 @@
 """The benchmark's own data: graphs, query pairs and arrival times.
 
-Copied from the program (``repro.graphs.generators``, ``repro.core.workload``
-and ``benchmarks/serving_perf.py``) so that a change to the program cannot
-move the data it is measured on. ``bench/tests/test_gen.py`` checks that the
-copies give the same edges and pairs as the originals for a fixed seed.
+A configuration's graph comes from the generator it names:
+``generator.name`` picks ``bench/graphs/<name>.py``, whose one function
+``generate(seed, **params)`` returns the edges (``params["n"]`` is the node
+count) as int64 arrays of a DAG, deduplicated and sorted by (src, dst), as
+``dedup`` gives them: the order the program's ``build_csr`` produces. A new
+graph shape is a new file there.
 
-Graphs are returned as deduplicated edge lists sorted by (src, dst), which is
-the order the program's ``build_csr`` produces, and as CSR arrays.
+The generators, pairs and arrivals are copied from the program
+(``repro.graphs.generators``, ``repro.core.workload`` and
+``benchmarks/serving_perf.py``) so that a change to the program cannot move
+the data it is measured on. ``bench/tests/test_harness_gen.py`` checks that
+the copies give the same edges and pairs as the originals for a fixed seed.
 """
 from __future__ import annotations
 
+from pathlib import Path
+
 import numpy as np
 
+from harness.loader import load_module
 
-def _dedup(n: int, src, dst):
+GRAPHS = Path(__file__).resolve().parents[1] / "graphs"
+
+
+def dedup(n: int, src, dst):
+    """Edges without duplicates, sorted by (src, dst), as int64 arrays."""
     key = np.unique(np.asarray(src, np.int64) * np.int64(n)
                     + np.asarray(dst, np.int64))
     return key // n, key % n
@@ -27,49 +39,20 @@ def csr(n: int, src, dst):
     return indptr, np.asarray(dst, np.int32)
 
 
-def random_dag(n: int, avg_deg: float, seed: int):
-    """Erdos-Renyi-style DAG, edges from lower to higher id."""
-    rng = np.random.default_rng(seed)
-    m = int(n * avg_deg)
-    src = rng.integers(0, n - 1, size=2 * m, dtype=np.int64)
-    dst = rng.integers(1, n, size=2 * m, dtype=np.int64)
-    lo = np.minimum(src, dst)
-    hi = np.maximum(src, dst)
-    keep = lo != hi
-    return _dedup(n, lo[keep][:m], hi[keep][:m])
-
-
-def layered_dag(n: int, n_layers: int, avg_deg: float, seed: int,
-                skip_p: float = 0.1):
-    """Citation-like DAG: nodes in layers, edges to later layers; a
-    ``skip_p`` share of edges skips two or more layers."""
-    rng = np.random.default_rng(seed)
-    layer = np.sort(rng.integers(0, n_layers, size=n))
-    m = int(n * avg_deg)
-    src = rng.integers(0, n, size=2 * m, dtype=np.int64)
-    jump = np.where(rng.random(2 * m) < skip_p,
-                    rng.integers(2, max(3, n_layers // 3), size=2 * m), 1)
-    tgt_layer = layer[src] + jump
-    lo = np.searchsorted(layer, tgt_layer, side="left")
-    hi = np.searchsorted(layer, tgt_layer, side="right")
-    ok = hi > lo
-    src, lo, hi = src[ok], lo[ok], hi[ok]
-    dst = lo + (rng.random(src.size) * (hi - lo)).astype(np.int64)
-    src, dst = src[:m], dst[:m]
-    keep = src != dst
-    return _dedup(n, src[keep], dst[keep])
-
-
-GENERATORS = {"random_dag": random_dag, "layered_dag": layered_dag}
+def generator_path(name: str) -> Path:
+    """The file of the graph generator ``name``, or exit before any work."""
+    path = GRAPHS / f"{name}.py"
+    if not path.exists():
+        raise SystemExit(f"bench: no generator {name!r} under bench/graphs/")
+    return path
 
 
 def make_graph(cfg: dict):
     """(n, src, dst) of a configuration's graph, from its ``graph_seed``."""
     gen = cfg["generator"]
-    n = int(gen["params"]["n"])
-    src, dst = GENERATORS[gen["name"]](**gen["params"],
-                                       seed=int(cfg["graph_seed"]))
-    return n, src, dst
+    src, dst = load_module(generator_path(gen["name"])).generate(
+        int(cfg["graph_seed"]), **gen["params"])
+    return int(gen["params"]["n"]), src, dst
 
 
 def random_queries(n: int, q: int, seed: int):

@@ -6,7 +6,8 @@
 One process runs one cell of ``BENCHMARK.json`` on the chips of the machine
 it starts on. Everything is found by name: the cell's configuration in
 ``bench/configs/<config>.json``, its traffic mix in
-``bench/traffic/<traffic>.json``, the driver of that mix's ``kind`` in
+``bench/traffic/<traffic>.json``, the generator of its graph in
+``bench/graphs/<generator.name>.py``, the driver of that mix's ``kind`` in
 ``bench/traffic/<kind>.py``, and the reader of each per-layer metric in
 ``bench/metrics/<metric>.py`` (or the file of the name without its last
 dotted part, which names the end-to-end metric it moves).
@@ -29,7 +30,6 @@ T_PROCESS = time.perf_counter()
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -47,6 +47,7 @@ for _p in (str(ROOT / "src"), str(HERE)):
 from harness import gen, peaks  # noqa: E402
 from harness import trace as tr  # noqa: E402
 from harness.index import open_session  # noqa: E402
+from harness.loader import load_module  # noqa: E402
 from harness.reference import Reference  # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -57,14 +58,6 @@ TRACE_SECONDS = 10.0            # a traced run's window, at most
 
 def log(msg: str) -> None:
     print(msg, flush=True)
-
-
-def load_module(path: Path):
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # ------------------------------------------------------------ by name --
@@ -96,6 +89,7 @@ def resolve(root: Path, name: str) -> dict:
     if not driver.exists():
         raise SystemExit(f"bench: no driver for traffic kind "
                          f"{traffic['kind']!r} ({driver})")
+    gen.generator_path(cfg["generator"]["name"])    # refused before the chips
     e2e = [m for m in bench["end_to_end"]
            if name in m.get("workloads", [name])]
     moved = {m["name"] for m in e2e}

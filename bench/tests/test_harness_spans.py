@@ -108,6 +108,5 @@ def test_program_spans_reach_the_trace_reader(tmp_path):
     _, host = tr.read_xplane(tr.find_xplane(str(tmp_path)),
                              host_names=tr.HOST_SPANS)
     names = {h[0] for h in host}
-    assert {"dispatch", "dispatch.h2d", "dispatch.gather",
-            "dispatch.classify", "phase1", "phase1.wait", "phase1.tally",
-            "stage.pad"} <= names, names
+    assert {"dispatch", "dispatch.h2d", "dispatch.classify", "phase1",
+            "phase1.wait", "phase1.tally", "stage.pad"} <= names, names
